@@ -1,4 +1,4 @@
-"""Distributed RP-vs-RC benchmark (paper Figs 12/13) on 8 virtual devices.
+"""Distributed RP-vs-RC benchmark (paper Figs 12/13) over the local devices.
 
 Measures warm-path steady-state throughput separately from the
 compile-inclusive cold path: every configuration ingests a few warmup
@@ -9,34 +9,51 @@ drains mid-run.  Alongside the wall numbers it records the warm-path
 accounting — compile events, cap-ladder rung transitions, overflow
 retries, partitioned-CSR uploads — plus the exchanged message slots for
 RIPPLE vs pull-based RC across partition counts (the paper's throughput
-and comm-cost scaling study, scaled to CPU).
+and comm-cost scaling study).
+
+Partition counts and meshes come from ``jax.device_count()``: every
+power-of-two count from 2 up to the device count, each on a
+``(parts, devices // parts)`` ("data", "model") mesh.  Only when the run
+is pinned to the CPU (``JAX_PLATFORMS=cpu``) does the script ask XLA for
+eight virtual host devices, so the sweep has devices to span.
 
 Writes ``BENCH_dist.json`` at the repo root: per (partition count, mode)
 steady ``updates_per_sec`` vs ``cold_updates_per_sec``, compile/ladder
-counters, comm slots, and CSR maintenance stats — the machine-readable
-perf trajectory.
+counters, comm slots, and CSR maintenance stats, with the platform and
+device kind they were measured on.
 """
 import json
 import os
 import sys
 import time
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+if os.environ.get("JAX_PLATFORMS") == "cpu" and \
+        "device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               "--xla_force_host_platform_device_count=8"
+                               ).strip()
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.api import InferenceSession, SessionConfig  # noqa: E402
-from repro.utils import make_mesh_compat, next_bucket  # noqa: E402
+from repro.launch.mesh import make_local_mesh  # noqa: E402
+from repro.utils import next_bucket, use_compile_cache  # noqa: E402
 
 D = 64
 WARMUP_BATCHES = 4
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_dist.json")
 
 
+def partition_counts(devices: int) -> tuple[int, ...]:
+    """Powers of two from 2 up to ``devices`` (just 1 on one device)."""
+    return tuple(p for p in (2, 4, 8, 16, 32) if p <= devices) or (1,)
+
+
 def run(parts: int, mode: str, n=1500, m=30000, batch=100, n_updates=1200,
         workload="gc-s", mix=(1.0, 1.0, 1.0)):
-    mesh = make_mesh_compat((parts, 8 // parts), ("data", "model"))
+    mesh = make_local_mesh(parts, jax.device_count() // parts)
     engine = "dist" if mode == "ripple" else "dist-rc"
     session = InferenceSession.build(SessionConfig(
         workload=workload, engine=engine,
@@ -119,13 +136,15 @@ def run(parts: int, mode: str, n=1500, m=30000, batch=100, n_updates=1200,
 
 
 def main():
+    use_compile_cache()
+    sweep = partition_counts(jax.device_count())
     records = []
-    for parts in (2, 4, 8):
+    for parts in sweep:
         for mode in ("ripple", "rc"):
             records.append(run(parts, mode))
     by = {(r["parts"], r["mode"]): r for r in records}
     reduction = {}
-    for parts in (2, 4, 8):
+    for parts in sweep:
         ratio = by[(parts, "rc")]["mean_comm_slots"] \
             / max(by[(parts, "ripple")]["mean_comm_slots"], 1e-9)
         reduction[str(parts)] = ratio
@@ -141,10 +160,11 @@ def main():
     # the incremental regime; gc-min because the non-self-dependent family
     # lets filtered propagation actually shed rows (SAGE's h^{l-1}
     # dependence keeps every frontier row alive regardless of aggregator).
+    mono_parts = max(p for p in sweep if p <= 4)
     mono = []
     for mode in ("ripple", "rc"):
-        mono.append(run(4, mode, workload="gc-min", n=3000, m=15000,
-                        batch=20, n_updates=300, mix=(1, 3, 1)))
+        mono.append(run(mono_parts, mode, workload="gc-min", n=3000,
+                        m=15000, batch=20, n_updates=300, mix=(1, 3, 1)))
     mono_ratio = mono[1]["mean_comm_slots"] \
         / max(mono[0]["mean_comm_slots"], 1e-9)
     pull_ratio = mono[1]["mean_pull_slots"] \
@@ -153,11 +173,14 @@ def main():
     # d_loc-wide rows per request, RIPPLE one scalar per shrunk-dim pull)
     resp_ratio = mono[1]["mean_pull_resp_units"] \
         / max(mono[0]["mean_pull_resp_units"], 1e-9)
-    print(f"fig12/comm-reduction/gc-min-p4,0.0,"
+    print(f"fig12/comm-reduction/gc-min-p{mono_parts},0.0,"
           f"rc_over_rp={mono_ratio:.1f}x pull_rc_over_rp={pull_ratio:.1f}x "
           f"resp_rc_over_rp={resp_ratio:.1f}x", flush=True)
     with open(OUT_PATH, "w") as f:
         json.dump({"bench": "dist", "workload": "gc-s", "n": 1500,
+                   "device": {"platform": jax.devices()[0].platform,
+                              "kind": jax.devices()[0].device_kind,
+                              "count": jax.device_count()},
                    "m": 30000, "batch": 100, "n_updates": 1200, "d": D,
                    "warmup_batches": WARMUP_BATCHES,
                    "results": records,

@@ -11,7 +11,6 @@ EXPERIMENTS.md §Paper-fidelity.
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
 import time
 
@@ -21,6 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import InferenceState  # noqa: E402
 from benchmarks.common import GRAPHS, engine_for, run_stream, setup  # noqa: E402
+from repro.utils import use_compile_cache  # noqa: E402
 
 ROWS: list[str] = []
 
@@ -127,19 +127,10 @@ def fig11_latency_vs_affected():
 def fig12_distributed():
     """Distributed RP vs RC: throughput + comm volume (Figs 12/13).
 
-    Runs in a subprocess with 8 virtual devices (XLA device-count must be
-    set before jax init)."""
-    script = os.path.join(os.path.dirname(__file__), "dist_bench.py")
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    res = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, timeout=1800, env=env)
-    if res.returncode:
-        emit("fig12/FAILED", 0.0, res.stderr.strip()[-200:].replace(",", ";"))
-        return
-    for line in res.stdout.strip().splitlines():
-        if line.startswith("fig12"):
-            parts = line.split(",", 2)
-            emit(parts[0], float(parts[1]), parts[2] if len(parts) > 2 else "")
+    Runs in this process over its devices (see dist_bench.py): a child
+    process could not reach an accelerator this process already holds."""
+    from benchmarks import dist_bench
+    dist_bench.main()
 
 
 def bench_single():
@@ -429,6 +420,7 @@ FIGS = {
 
 def main() -> None:
     which = sys.argv[1:] or list(FIGS)
+    use_compile_cache()
     print("name,us_per_call,derived")
     for name in which:
         FIGS[name]()

@@ -32,6 +32,7 @@ import numpy as np  # noqa: E402
 from repro.api import InferenceSession, SessionConfig  # noqa: E402
 from repro.serve import (ClosedLoopLoad, GraphServer, OpenLoopLoad,  # noqa: E402
                          latency_summary, split_stream)
+from repro.utils import use_compile_cache  # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_serve.json")
 
@@ -225,6 +226,7 @@ def bench_engine(engine, cfg) -> dict:
 
 
 def main():
+    use_compile_cache()
     smoke = os.environ.get("RIPPLE_BENCH_SMOKE") == "1"
     cfg = _cfg(smoke)
     out = {"bench": "serve", "smoke": smoke, "config": cfg,

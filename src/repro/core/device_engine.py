@@ -54,10 +54,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
+
 from .aggregators import (MAX, certified_error_bound, deferral_budgets,
                           jnp_segment_extremum)
 from .graph import _GROW, _MIN_SLACK, DynamicGraph, flat_row_indices
-from .workloads import Workload
+from .workloads import Workload, matmul_f32
 
 
 class DeviceCSR(NamedTuple):
@@ -390,7 +392,7 @@ def _apply_hop(workload: Workload, params_l: dict, layer: int, n: int,
                                         params_l["w_nbr"], params_l["b"],
                                         mean=mean, relu=False,
                                         interpret=interpret)
-            h_new = h_new + h_prev @ params_l["w_self"]
+            h_new = h_new + matmul_f32(h_prev, params_l["w_self"])
             if not last:
                 h_new = jnp.maximum(h_new, 0.0)
     elif pallas and workload.family == "gin":
@@ -415,7 +417,7 @@ def _propagate_impl(workload: Workload, n: int,
                     caps: tuple[tuple[int, int], ...],
                     params: list[dict], state: DeviceState, csr: DeviceCSR,
                     batch: BatchDev, *, pallas: bool = False,
-                    interpret: bool = True):
+                    interpret: bool):
     """One full L-hop incremental propagation of a routed batch.
 
     caps[l] = (frontier_cap entering hop l+1 computation, edge_cap at hop l).
@@ -697,7 +699,7 @@ def _monotonic_hop(workload: Workload, params_l: dict, layer: int, n: int,
                                           reagg=RG, mask=MK,
                                           maximize=maximize, relu=False,
                                           interpret=interpret)
-            h_new = h_new + h_prev @ params_l["w_self"]
+            h_new = h_new + matmul_f32(h_prev, params_l["w_self"])
             if not last:
                 h_new = jnp.maximum(h_new, 0.0)
     else:
@@ -719,7 +721,7 @@ def _propagate_monotonic_impl(workload: Workload, n: int,
                               params: list[dict], state: DeviceState,
                               out_csr: DeviceCSR, in_csr: DeviceCSR,
                               batch: BatchDev, *, pallas: bool = False,
-                              interpret: bool = True):
+                              interpret: bool):
     """L-hop monotonic (max/min) propagation of a routed batch.
 
     caps[l] = (row_cap, edge_cap, pull_cap, pair_cap) at hop l; pull_cap
@@ -886,7 +888,7 @@ def _propagate_bounded_impl(workload: Workload, n: int,
                             params: list[dict], state: DeviceState,
                             out_csr: DeviceCSR, in_csr: DeviceCSR,
                             batch: BatchDev, taus: jax.Array, *,
-                            pallas: bool = False, interpret: bool = True):
+                            pallas: bool = False, interpret: bool):
     """L-hop bounded (attention/top-k/PNA) propagation of a routed batch.
 
     caps[l] = (row_cap, edge_cap, pull_cap, indeg_cap); pull_cap bounds the
@@ -1018,7 +1020,10 @@ class DeviceEngine:
         self.use_pallas = use_pallas
         self.async_dispatch = async_dispatch
         self.debug_checks = debug_checks
-        self.interpret = jax.default_backend() != "tpu"
+        self.interpret = interpret_mode()
+        # host-memory backend: device arrays are host arrays, so the
+        # serving commit log indexes them instead of gathering on device
+        self._host_backend = jax.default_backend() == "cpu"
         self.out_mirror = DeviceCSRMirror(graph.out)
         self.in_mirror = DeviceCSRMirror(graph.inn) \
             if (self.monotonic or self.bounded) else None
@@ -1214,27 +1219,34 @@ class DeviceEngine:
         return dev_batch, out_rows, in_rows
 
     # -- dispatch / resolve ------------------------------------------------
-    def _run(self, dev_batch: BatchDev, caps: tuple):
+    def _call(self, dev_batch: BatchDev, caps: tuple):
+        """The family's jitted propagate with its positional arguments."""
+        head = (self.workload, self.n, caps, self.params, self.state,
+                self.out_mirror.device())
         if self.bounded:
             fn = propagate_bounded_donated if self.donate \
                 else propagate_bounded
-            return fn(self.workload, self.n, caps, self.params, self.state,
-                      self.out_mirror.device(), self.in_mirror.device(),
-                      dev_batch, self._taus(), pallas=self.use_pallas,
-                      interpret=self.interpret)
+            return fn, head + (self.in_mirror.device(), dev_batch,
+                               self._taus())
         if self.monotonic:
             fn = propagate_monotonic_donated if self.donate \
                 else propagate_monotonic
-            return fn(self.workload, self.n, caps, self.params, self.state,
-                      self.out_mirror.device(), self.in_mirror.device(),
-                      dev_batch, pallas=self.use_pallas,
-                      interpret=self.interpret)
-        fn = propagate_donated if self.donate else propagate
-        new_state, final, overflow, sizes = fn(
-            self.workload, self.n, caps, self.params, self.state,
-            self.out_mirror.device(), dev_batch, pallas=self.use_pallas,
-            interpret=self.interpret)
-        return new_state, final, overflow, sizes, None
+            return fn, head + (self.in_mirror.device(), dev_batch)
+        return (propagate_donated if self.donate else propagate), \
+            head + (dev_batch,)
+
+    def _run(self, dev_batch: BatchDev, caps: tuple):
+        fn, args = self._call(dev_batch, caps)
+        out = fn(*args, pallas=self.use_pallas, interpret=self.interpret)
+        return out if len(out) == 5 else (*out, None)
+
+    def compiled_propagate_text(self) -> str:
+        """Optimized HLO of the propagate at the current rung-0 caps, so a
+        caller can check which kernels the backend compiler placed in it
+        (a Pallas kernel compiled for a TPU is a ``tpu_custom_call``)."""
+        fn, args = self._call(self._sentinel_batch(), self._caps(0))
+        return fn.lower(*args, pallas=self.use_pallas,
+                        interpret=self.interpret).compile().as_text()
 
     def _dispatch(self, dev_batch: BatchDev) -> None:
         assert self._pending is None
@@ -1314,7 +1326,7 @@ class DeviceEngine:
             if not aff.size:
                 rows = np.zeros((0, int(self.state.H[-1].shape[1])),
                                 np.float32)
-            elif jax.default_backend() == "cpu":
+            elif self._host_backend:
                 # host backend: np.asarray is ~zero-copy, a device gather
                 # dispatch costs ~100x more than indexing on the host
                 rows = np.asarray(self.state.H[-1])[aff]

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.utils import next_bucket, shard_map_compat
+from repro.utils import next_bucket
 from .distributed import (DistBatch, DistCSR, make_monotonic_propagate,
                           make_rc_propagate, make_ripple_propagate,
                           tp_param_specs)
@@ -203,10 +203,10 @@ class PartitionedCSR:
             return col[None], w[None], length[None]
 
         spec = P(self.dspec, None)
-        sm = shard_map_compat(local, mesh=self.mesh,
-                              in_specs=(spec, spec, spec) + (P(),) * 7,
-                              out_specs=(spec, spec, spec),
-                              check_vma=False)
+        sm = jax.shard_map(local, mesh=self.mesh,
+                           in_specs=(spec, spec, spec) + (P(),) * 7,
+                           out_specs=(spec, spec, spec),
+                           check_vma=False)
         fn = jax.jit(sm, donate_argnums=(0, 1, 2))
         self._scatter_cache[key] = fn
         return fn

@@ -54,7 +54,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.utils import shard_map_compat
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -62,7 +61,7 @@ from .aggregators import jnp_segment_extremum
 from .device_engine import _compact_mailbox, _masked_pairs
 from .graph import DynamicGraph
 from .partition import Partitioning, ldg_partition
-from .workloads import Workload
+from .workloads import Workload, matmul_f32
 
 _F32_EXACT = 1 << 24   # ids ride collectives as float32 below this
 
@@ -77,7 +76,8 @@ def tp_update(workload: Workload, params_l: dict, layer: int,
     fam = workload.family
 
     def rp_matmul(a, w):  # row-parallel: a [R, d_in/M] @ w [d_in/M, d_out]
-        return jax.lax.psum_scatter(a @ w, axis, scatter_dimension=1, tiled=True)
+        return jax.lax.psum_scatter(matmul_f32(a, w), axis,
+                                    scatter_dimension=1, tiled=True)
 
     if fam == "gc":
         out = rp_matmul(x, params_l["w"]) + params_l["b"]
@@ -556,7 +556,7 @@ def make_ripple_propagate(mesh, workload: Workload, n_local: int,
         del_src=P(dax, None), del_dst=P(dax, None), del_w=P(dax, None))
     csr_spec = DistCSR(col=P(dax, None), w=P(dax, None),
                        start=P(dax, None), length=P(dax, None))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tp_param_specs(workload), state_spec_h, state_spec_s,
                   P(dax, None), csr_spec, batch_spec),
@@ -841,7 +841,7 @@ def make_monotonic_propagate(mesh, workload: Workload, n_local: int,
         del_src=P(dax, None), del_dst=P(dax, None), del_w=P(dax, None))
     csr_spec = DistCSR(col=P(dax, None), w=P(dax, None),
                        start=P(dax, None), length=P(dax, None))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tp_param_specs(workload), state_spec_h, state_spec_s,
                   state_spec_s, P(dax, None), csr_spec, csr_spec, batch_spec),
@@ -965,7 +965,7 @@ def make_rc_propagate(mesh, workload: Workload, n_local: int,
         del_src=P(dax, None), del_dst=P(dax, None), del_w=P(dax, None))
     csr_spec = DistCSR(col=P(dax, None), w=P(dax, None),
                        start=P(dax, None), length=P(dax, None))
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tp_param_specs(workload), state_spec_h, state_spec_s,
                   P(dax, None), csr_spec, csr_spec, batch_spec),

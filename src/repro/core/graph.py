@@ -208,19 +208,43 @@ class DynamicGraph:
         Deletions are returned with the weight the edge had in the store,
         which the engine needs to retract the old contribution exactly.
         No-ops (duplicate adds, missing deletes) are dropped, matching the
-        idempotent semantics a production ingest layer provides.
+        idempotent semantics a production ingest layer provides.  So is an
+        edge the batch adds and then deletes again: the engines take a
+        batch's adds and deletes as simultaneous, and a max/min aggregate
+        would fold in the add while the delete, of an edge it never
+        recorded as a contributor, retracts nothing.
         """
         adds: list[EdgeUpdate] = []
         dels: list[EdgeUpdate] = []
-        for e in edges:
+        for e in self._net_updates(edges):
             if e.add:
-                if self.add_edge(e.src, e.dst, e.weight):
-                    adds.append(e)
+                self.add_edge(e.src, e.dst, e.weight)
+                adds.append(e)
             else:
                 w = self.delete_edge(e.src, e.dst)
-                if w is not None:
-                    dels.append(EdgeUpdate(e.src, e.dst, False, w))
+                dels.append(EdgeUpdate(e.src, e.dst, False, w))
         return adds, dels
+
+    def _net_updates(self, edges: Sequence[EdgeUpdate]) -> list[EdgeUpdate]:
+        """The batch's edge updates that change the store, in order, less
+        each add whose edge a later update of the batch deletes (and that
+        delete)."""
+        present: dict[tuple[int, int], bool] = {}
+        added_at: dict[tuple[int, int], int] = {}
+        net: list[EdgeUpdate | None] = []
+        for e in edges:
+            key = (e.src, e.dst)
+            if e.add == present.get(key, key in self._edge_set):
+                continue  # duplicate add or missing delete
+            present[key] = e.add
+            if e.add:
+                added_at[key] = len(net)
+                net.append(e)
+            elif key in added_at:
+                net[added_at.pop(key)] = None
+            else:
+                net.append(e)
+        return [e for e in net if e is not None]
 
     # -- export ----------------------------------------------------------
     def csr_out(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

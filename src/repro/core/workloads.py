@@ -37,19 +37,31 @@ import numpy as np
 from .aggregators import Aggregator, get_aggregator
 
 
+def matmul_f32(a, b):
+    """Matrix product at full float32 precision.  A TPU runs a float32
+    ``jnp`` product on bf16 passes by default, which at width 128 puts
+    the final layer ~1e-1 away from a float32 reference; NumPy operands
+    take the plain product."""
+    if isinstance(a, np.ndarray) and isinstance(b, np.ndarray):
+        return a @ b
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _gc_update(xp, p, h_prev, x, *, last: bool):
-    out = x @ p["w"] + p["b"]
+    out = matmul_f32(x, p["w"]) + p["b"]
     return out if last else xp.maximum(out, 0.0)
 
 
 def _sage_update(xp, p, h_prev, x, *, last: bool):
-    out = h_prev @ p["w_self"] + x @ p["w_nbr"] + p["b"]
+    out = matmul_f32(h_prev, p["w_self"]) + matmul_f32(x, p["w_nbr"]) \
+        + p["b"]
     return out if last else xp.maximum(out, 0.0)
 
 
 def _gin_update(xp, p, h_prev, x, *, last: bool):
     z = (1.0 + p["eps"]) * h_prev + x
-    out = xp.maximum(z @ p["w1"] + p["b1"], 0.0) @ p["w2"] + p["b2"]
+    out = matmul_f32(xp.maximum(matmul_f32(z, p["w1"]) + p["b1"], 0.0),
+                     p["w2"]) + p["b2"]
     return out if last else xp.maximum(out, 0.0)
 
 

@@ -8,6 +8,9 @@ one MXU matmul over W tiles, one write of (S', h).
 Grid: (row_tiles, out_tiles, k_tiles); the S'+normalize epilogue fires on
 the first k step, accumulation in an fp32 VMEM scratch, bias+activation on
 the last k step.  Tiles are MXU-aligned (multiples of 128 where dims allow).
+The degree vector and the bias travel as 2-D ``[R, 1]`` / ``[1, Dout]``
+blocks: Mosaic tiles a 1-D block in 128-lane units, which a ``(row_tile,)``
+block of the degree vector does not match once R exceeds one tile.
 """
 from __future__ import annotations
 
@@ -31,9 +34,10 @@ def _kernel(S_ref, M_ref, k_ref, W_ref, b_ref, Snew_ref, h_ref, acc_ref,
     Snew_ref[...] = S_new  # write-back (same value for every j tile)
     x = S_new
     if mean:
-        x = x / jnp.maximum(k_ref[...], 1.0)[:, None]
+        x = x / jnp.maximum(k_ref[...], 1.0)
     acc_ref[...] += jnp.dot(x.astype(jnp.float32), W_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(kk == n_k - 1)
     def _fin():
@@ -47,7 +51,7 @@ def _kernel(S_ref, M_ref, k_ref, W_ref, b_ref, Snew_ref, h_ref, acc_ref,
                                              "k_tile", "out_tile", "interpret"))
 def delta_apply_pallas(S, mailbox, k, W, b, *, mean: bool, relu: bool,
                        row_tile: int = 128, k_tile: int = 128,
-                       out_tile: int = 128, interpret: bool = True):
+                       out_tile: int = 128, interpret: bool):
     R, Din = S.shape
     Dout = W.shape[1]
     row_tile = min(row_tile, R)
@@ -64,9 +68,9 @@ def delta_apply_pallas(S, mailbox, k, W, b, *, mean: bool, relu: bool,
         in_specs=[
             pl.BlockSpec((row_tile, k_tile), lambda i, j, kk: (i, kk)),   # S
             pl.BlockSpec((row_tile, k_tile), lambda i, j, kk: (i, kk)),   # M
-            pl.BlockSpec((row_tile,), lambda i, j, kk: (i,)),             # k
+            pl.BlockSpec((row_tile, 1), lambda i, j, kk: (i, 0)),         # k
             pl.BlockSpec((k_tile, out_tile), lambda i, j, kk: (kk, j)),   # W
-            pl.BlockSpec((out_tile,), lambda i, j, kk: (j,)),             # b
+            pl.BlockSpec((1, out_tile), lambda i, j, kk: (0, j)),         # b
         ],
         out_specs=[
             pl.BlockSpec((row_tile, k_tile), lambda i, j, kk: (i, kk)),   # S'
@@ -76,4 +80,5 @@ def delta_apply_pallas(S, mailbox, k, W, b, *, mean: bool, relu: bool,
                    jax.ShapeDtypeStruct((R, Dout), S.dtype)],
         scratch_shapes=[pltpu.VMEM((row_tile, out_tile), jnp.float32)],
         interpret=interpret,
-    )(S, mailbox, k, W, b)
+        name="delta_apply",
+    )(S, mailbox, k.reshape(R, 1), W, b.reshape(1, Dout))

@@ -16,7 +16,7 @@ def _pad_to(x, mult, axis):
 
 
 def delta_apply(S, mailbox, k, W, b, *, mean: bool = False, relu: bool = True,
-                interpret: bool = True):
+                interpret: bool):
     """Fused S' = S + M; h = act(norm(S')@W + b).  Pads to 128-tiles."""
     R0, Din0 = S.shape
     Dout0 = W.shape[1]

@@ -16,8 +16,10 @@ MXU matmul over W tiles, one write of (S', h).
 Grid: (row_tiles, out_tiles, k_tiles); the extremum+mask epilogue fires on
 every k step (cheap, VPU), accumulation in an fp32 VMEM scratch, bias +
 activation on the last k step.  Tiles are MXU-aligned (multiples of 128
-where dims allow).  Contributor-ref maintenance stays outside the kernel:
-it is gather/compare bound, not matmul bound.
+where dims allow); the bias travels as a 2-D ``[1, Dout]`` block, since
+Mosaic refuses a 1-D ``(out_tile,)`` block once Dout spans several tiles.
+Contributor-ref maintenance stays outside the kernel: it is gather/compare
+bound, not matmul bound.
 """
 from __future__ import annotations
 
@@ -42,7 +44,8 @@ def _kernel(S_ref, M_ref, W_ref, b_ref, Snew_ref, h_ref, acc_ref,
     Snew_ref[...] = S_new  # write-back (same value for every j tile)
     x = jnp.where(jnp.isfinite(S_new), S_new, 0.0)
     acc_ref[...] += jnp.dot(x.astype(jnp.float32), W_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(kk == n_k - 1)
     def _fin():
@@ -71,7 +74,8 @@ def _kernel_masked(S_ref, M_ref, RG_ref, Mk_ref, W_ref, b_ref, Snew_ref,
     Snew_ref[...] = S_new  # write-back (same value for every j tile)
     x = jnp.where(jnp.isfinite(S_new), S_new, 0.0)
     acc_ref[...] += jnp.dot(x.astype(jnp.float32), W_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(kk == n_k - 1)
     def _fin():
@@ -86,7 +90,7 @@ def _kernel_masked(S_ref, M_ref, RG_ref, Mk_ref, W_ref, b_ref, Snew_ref,
 def extremum_apply_pallas(S, mailbox, W, b, reagg=None, mask=None, *,
                           maximize: bool, relu: bool,
                           row_tile: int = 128, k_tile: int = 128,
-                          out_tile: int = 128, interpret: bool = True):
+                          out_tile: int = 128, interpret: bool):
     R, Din = S.shape
     Dout = W.shape[1]
     row_tile = min(row_tile, R)
@@ -106,9 +110,9 @@ def extremum_apply_pallas(S, mailbox, W, b, reagg=None, mask=None, *,
         args += [reagg, mask]
     in_specs += [
         pl.BlockSpec((k_tile, out_tile), lambda i, j, kk: (kk, j)),   # W
-        pl.BlockSpec((out_tile,), lambda i, j, kk: (j,)),             # b
+        pl.BlockSpec((1, out_tile), lambda i, j, kk: (0, j)),         # b
     ]
-    args += [W, b]
+    args += [W, b.reshape(1, Dout)]
 
     kern = functools.partial(_kernel_masked if masked else _kernel,
                              maximize=maximize, relu=relu, n_k=n_k)
@@ -124,4 +128,5 @@ def extremum_apply_pallas(S, mailbox, W, b, reagg=None, mask=None, *,
                    jax.ShapeDtypeStruct((R, Dout), S.dtype)],
         scratch_shapes=[pltpu.VMEM((row_tile, out_tile), jnp.float32)],
         interpret=interpret,
+        name="extremum_apply_masked" if masked else "extremum_apply",
     )(*args)

@@ -23,7 +23,7 @@ def _pad_to(x, mult, axis, fill=0.0):
 
 def extremum_apply(S, mailbox, W, b, *, reagg=None, mask=None,
                    maximize: bool = True, relu: bool = True,
-                   interpret: bool = True):
+                   interpret: bool):
     """Fused S' = extremum(S, M); h = act(finite(S')@W + b).  128-tiles.
 
     With ``reagg``/``mask`` (the per-dim SHRINK variant) the base rows are

@@ -62,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("bq", "bkv", "interpret"))
 def flash_attention_pallas(q, k, v, *, bq: int = 128, bkv: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q [B,S,H,Dh]; k/v [B,S,Hkv,Dh] -> [B,S,H,Dh] (causal)."""
     B, S, H, Dh = q.shape
     Hkv = k.shape[2]

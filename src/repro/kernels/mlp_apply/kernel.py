@@ -17,7 +17,8 @@ are loaded whole per step — GIN hidden widths in this repo are O(128), so
 W1 and the W2 column tile sit comfortably in VMEM and no k-loop carry for
 the *hidden* activation is needed (an h1 scratch would otherwise have to
 persist across two grid axes).  ``eps`` is a traced scalar and travels in
-SMEM.
+SMEM; the degree vector and biases travel as 2-D ``[R, 1]`` / ``[1, D]``
+blocks, the layout Mosaic accepts for them at every row count.
 """
 from __future__ import annotations
 
@@ -35,14 +36,16 @@ def _kernel(eps_ref, S_ref, M_ref, Hp_ref, k_ref, W1_ref, b1_ref, W2_ref,
     Snew_ref[...] = S_new  # write-back (same value for every j tile)
     x = S_new
     if mean:
-        x = x / jnp.maximum(k_ref[...], 1.0)[:, None]
+        x = x / jnp.maximum(k_ref[...], 1.0)
     z = (1.0 + eps_ref[0, 0]) * Hp_ref[...] + x
     h1 = jnp.maximum(
         jnp.dot(z.astype(jnp.float32), W1_ref[...].astype(jnp.float32),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
         + b1_ref[...].astype(jnp.float32), 0.0)
     h = jnp.dot(h1, W2_ref[...].astype(jnp.float32),
-                preferred_element_type=jnp.float32) \
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST) \
         + b2_ref[...].astype(jnp.float32)
     if relu:
         h = jnp.maximum(h, 0.0)
@@ -53,7 +56,7 @@ def _kernel(eps_ref, S_ref, M_ref, Hp_ref, k_ref, W1_ref, b1_ref, W2_ref,
                                              "out_tile", "interpret"))
 def mlp_apply_pallas(eps, S, mailbox, h_prev, k, W1, b1, W2, b2, *,
                      mean: bool, relu: bool, row_tile: int = 128,
-                     out_tile: int = 128, interpret: bool = True):
+                     out_tile: int = 128, interpret: bool):
     R, Din = S.shape
     Dh = W1.shape[1]
     Dout = W2.shape[1]
@@ -71,11 +74,11 @@ def mlp_apply_pallas(eps, S, mailbox, h_prev, k, W1, b1, W2, b2, *,
             pl.BlockSpec((row_tile, Din), lambda i, j: (i, 0)),    # S
             pl.BlockSpec((row_tile, Din), lambda i, j: (i, 0)),    # M
             pl.BlockSpec((row_tile, Din), lambda i, j: (i, 0)),    # h_prev
-            pl.BlockSpec((row_tile,), lambda i, j: (i,)),          # k
+            pl.BlockSpec((row_tile, 1), lambda i, j: (i, 0)),      # k
             pl.BlockSpec((Din, Dh), lambda i, j: (0, 0)),          # W1
-            pl.BlockSpec((Dh,), lambda i, j: (0,)),                # b1
+            pl.BlockSpec((1, Dh), lambda i, j: (0, 0)),            # b1
             pl.BlockSpec((Dh, out_tile), lambda i, j: (0, j)),     # W2
-            pl.BlockSpec((out_tile,), lambda i, j: (j,)),          # b2
+            pl.BlockSpec((1, out_tile), lambda i, j: (0, j)),      # b2
         ],
         out_specs=[
             pl.BlockSpec((row_tile, Din), lambda i, j: (i, 0)),    # S'
@@ -84,4 +87,6 @@ def mlp_apply_pallas(eps, S, mailbox, h_prev, k, W1, b1, W2, b2, *,
         out_shape=[jax.ShapeDtypeStruct((R, Din), S.dtype),
                    jax.ShapeDtypeStruct((R, Dout), S.dtype)],
         interpret=interpret,
-    )(eps, S, mailbox, h_prev, k, W1, b1, W2, b2)
+        name="mlp_apply",
+    )(eps, S, mailbox, h_prev, k.reshape(R, 1), W1, b1.reshape(1, Dh), W2,
+      b2.reshape(1, Dout))

@@ -22,7 +22,7 @@ def _pad_to(x, mult, axis):
 
 
 def mlp_apply(S, mailbox, h_prev, k, eps, W1, b1, W2, b2, *,
-              mean: bool = False, relu: bool = True, interpret: bool = True):
+              mean: bool = False, relu: bool = True, interpret: bool):
     """Fused S' = S + M; h = act(relu(((1+eps)h + norm(S'))@W1+b1)@W2+b2)."""
     R0, Din0 = S.shape
     Dh0 = W1.shape[1]

@@ -40,7 +40,7 @@ def _spmm_kernel(a_idx_ref, x_idx_ref, a_ref, x_ref, o_ref):
                                     "interpret"))
 def bsr_spmm(a_idx: jax.Array, x_idx: jax.Array, a_blocks: jax.Array,
              x: jax.Array, *, n_row_blocks: int, max_k: int, blk: int,
-             d_tile: int | None = None, interpret: bool = True) -> jax.Array:
+             d_tile: int | None = None, interpret: bool) -> jax.Array:
     """a_blocks [nnzb+1, blk, blk] (last tile all-zero pad);
     a_idx/x_idx [n_row_blocks, max_k]; x [n_col_blocks*blk, d]."""
     d = x.shape[1]

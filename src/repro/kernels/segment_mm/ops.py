@@ -1,4 +1,4 @@
-"""jit'd wrapper: COO edge list -> BSR -> Pallas SpMM (+CPU interpret mode)."""
+"""jit'd wrapper: COO edge list -> BSR -> Pallas SpMM."""
 from __future__ import annotations
 
 import numpy as np
@@ -37,8 +37,8 @@ def coo_to_bsr(src: np.ndarray, dst: np.ndarray, w: np.ndarray, n: int,
     return a_idx, x_idx, a_blocks, nbr, n_pad
 
 
-def segment_mm(src, dst, w, x, n: int, blk: int = 128,
-               interpret: bool = True) -> jax.Array:
+def segment_mm(src, dst, w, x, n: int, blk: int = 128, *,
+               interpret: bool) -> jax.Array:
     """Drop-in for ref.segment_mm_ref using the Pallas BSR kernel."""
     src = np.asarray(src)
     dst = np.asarray(dst)

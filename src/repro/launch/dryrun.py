@@ -16,13 +16,13 @@ import traceback       # noqa: E402
 
 import jax             # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
 
 from repro.configs.registry import ARCHS, get_arch   # noqa: E402
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS,  # noqa: E402
                                make_production_mesh)
-from repro.utils import human_bytes, human_count     # noqa: E402
+from repro.utils import (human_bytes, human_count,   # noqa: E402
+                         use_compile_cache)
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
                 "s32": 4, "u32": 4, "s16": 2, "u16": 2, "s8": 1, "u8": 1,
@@ -154,6 +154,7 @@ def main():
                     default="single")
     ap.add_argument("--out", default=None, help="append JSONL here")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.arch == "all":
         names = [a for a in ARCHS if a != "ripple-papers"]

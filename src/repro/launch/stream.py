@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 
 from repro.api import InferenceSession, SessionConfig, engine_names
+from repro.utils import use_compile_cache
 
 
 def build(args) -> InferenceSession:
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
 
+    use_compile_cache()
     session = build(args)
     stream = session.make_stream(args.updates, seed=1)
     report = session.ingest(stream, batch_size=args.batch_size,

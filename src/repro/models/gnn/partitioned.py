@@ -20,7 +20,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.utils import shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 from repro.models.gnn.common import (cosine_cutoff, gaussian_rbf, init_mlp,
@@ -107,7 +106,7 @@ def make_partitioned_schnet(mesh, *, n_local: int, e_cap: int, halo_cap: int,
     edge_spec = PartEdges(src_local=P(data_axes, None),
                           dst_global=P(data_axes, None),
                           dist=P(data_axes, None), mask=P(data_axes, None))
-    loss_sharded = shard_map_compat(
+    loss_sharded = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(P(),  # params replicated (pytree-prefix spec)
                   P(data_axes, None, None), edge_spec, P(data_axes, None)),
@@ -184,7 +183,7 @@ def make_partitioned_schnet_v2(mesh, *, n_local: int, cap2: int, d_in: int,
                             dst_local=P(data_axes, None, None),
                             dist=P(data_axes, None, None),
                             mask=P(data_axes, None, None))
-    loss_sharded = shard_map_compat(
+    loss_sharded = jax.shard_map(
         local_loss, mesh=mesh,
         in_specs=(P(), P(data_axes, None, None), edge_spec,
                   P(data_axes, None)),

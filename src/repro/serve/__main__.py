@@ -14,6 +14,7 @@ import argparse
 import numpy as np
 
 from repro.api import InferenceSession, SessionConfig
+from repro.utils import use_compile_cache
 
 from . import (ClosedLoopLoad, GraphServer, OpenLoopLoad, latency_summary,
                split_stream)
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     session = InferenceSession.build(SessionConfig(
         workload=args.workload, engine=args.engine, n=args.n, m=args.m,
         seed=args.seed, deadline_ms=args.deadline_ms))

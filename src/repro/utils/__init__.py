@@ -1,9 +1,14 @@
-"""Shared small utilities: padding, bucketing, tree math."""
+"""Shared small utilities: padding, bucketing, the compile-cache home."""
 from __future__ import annotations
 
 import math
+import os
 
 import numpy as np
+
+# src/repro/utils/__init__.py -> the checkout root
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def next_bucket(n: int, *, minimum: int = 16) -> int:
@@ -27,34 +32,22 @@ def pad_to(arr: np.ndarray, size: int, fill=0) -> np.ndarray:
     return np.pad(arr, pad_width, constant_values=fill)
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; older ones
-    default to Auto axes anyway."""
-    import jax
+def use_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed home; returns it.
 
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``check_vma``; older
-    releases have ``jax.experimental.shard_map.shard_map`` with the same
-    positional contract and the flag spelled ``check_rep``.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache``: a fixed path, so that a later run of the
+    same checkout finds what an earlier one compiled.
     """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def human_bytes(n: float) -> str:

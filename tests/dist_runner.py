@@ -26,7 +26,7 @@ import jax  # noqa: E402
 
 from repro.api import InferenceSession, SessionConfig, engine_names  # noqa: E402
 from repro.core import full_inference  # noqa: E402
-from repro.utils import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import make_local_mesh  # noqa: E402
 
 ATOL = 3e-3
 
@@ -58,7 +58,7 @@ def assert_exact(session, label: str) -> None:
 
 def run(mode: str, name: str) -> None:
     """Session oracle exactness per batch, one workload x one dist mode."""
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_local_mesh(4, 2)
     engine = "dist" if mode == "ripple" else "dist-rc"
     s = build(name, engine, {"mesh": mesh})
     updates = list(s.make_stream(15, seed=1))
@@ -76,7 +76,8 @@ def run(mode: str, name: str) -> None:
 
 def run_multipod() -> None:
     """Vertex partition spanning two mesh axes: ("pod", "data") x model."""
-    mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     s = build("gc-m", "dist", {"mesh": mesh, "data_axes": ("pod", "data")})
     assert s.engine.impl.n_parts == 4 and s.engine.impl.M == 2
     s.ingest(s.make_stream(15, seed=1), batch_size=5)
@@ -94,7 +95,7 @@ def run_warm_equiv() -> None:
     every workload at 2 and 8 virtual shards — the gated-commit contract
     behind donation and overlap."""
     for parts in (2, 8):
-        mesh = make_mesh_compat((parts, 8 // parts), ("data", "model"))
+        mesh = make_local_mesh(parts, 8 // parts)
         for name in ("gc-s", "gs-s", "gc-m", "gi-s", "gc-w",
                      "gs-max", "gc-min"):
             variants = ({"donate": False, "warm": False},
@@ -120,7 +121,7 @@ def run_overflow_commit() -> None:
     ladder retry then lands the batch exactly."""
     from repro.core.graph import UpdateBatch
 
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_local_mesh(4, 2)
     s = build("gs-max", "dist", {"mesh": mesh})
     ups = list(s.make_stream(12, seed=3))
     s.ingest(ups[:6])
@@ -151,7 +152,7 @@ def run_overflow_commit() -> None:
 
 def run_swap_roundtrip() -> None:
     """ripple -> dist -> device mid-stream == never swapping at all."""
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_local_mesh(4, 2)
     a = build("gc-m", "ripple", {})
     b = build("gc-m", "ripple", {})
     ups_a = list(a.make_stream(30, seed=1))
@@ -177,8 +178,8 @@ def run_ckpt_geometry_change() -> None:
     import glob
     import json
 
-    mesh_a = make_mesh_compat((4, 2), ("data", "model"))
-    mesh_b = make_mesh_compat((2, 4), ("data", "model"))
+    mesh_a = make_local_mesh(4, 2)
+    mesh_b = make_local_mesh(2, 4)
     tmp = tempfile.mkdtemp(prefix="dist_ckpt_")
     s = build("gc-s", "dist", {"mesh": mesh_a}, ckpt_dir=tmp,
               ckpt_every=10_000)
@@ -212,8 +213,8 @@ def run_elastic_resize() -> None:
     different partition count, and the resized engine keeps serving."""
     from repro.core.elastic import elastic_resize
 
-    mesh_a = make_mesh_compat((4, 2), ("data", "model"))
-    mesh_b = make_mesh_compat((2, 4), ("data", "model"))
+    mesh_a = make_local_mesh(4, 2)
+    mesh_b = make_local_mesh(2, 4)
     s = build("gs-s", "dist", {"mesh": mesh_a})
     updates = list(s.make_stream(20, seed=1))
     s.ingest(updates[:10], batch_size=5)

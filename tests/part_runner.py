@@ -37,8 +37,8 @@ def main():
     ref = np.asarray(schnet_forward(params, g, n_rbf=20, cutoff=6.0))
 
     # partitioned
-    from repro.utils import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     edges, n_local, e_cap = partition_graph_for_push(n, src, dst, dist, P_)
     step, edge_spec = make_partitioned_schnet(
         mesh, n_local=n_local, e_cap=e_cap, halo_cap=m, d_in=d_in,

@@ -49,6 +49,22 @@ def test_device_engine_matches_oracle(name):
                                        err_msg=f"{name} layer {l} step {step}")
 
 
+@pytest.mark.parametrize("name", ["gs-max", "gc-min", "gc-s"])
+def test_device_add_then_delete_in_one_batch(name):
+    """A new edge added and deleted again in one batch changes nothing."""
+    wl, g, params, state = _setup(name)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=16)
+    u, v = 0, 1
+    while g.has_edge(u, v) or u == v:
+        v += 1
+    eng.apply_batch(UpdateBatch(edges=[EdgeUpdate(u, v, True, 1.0),
+                                       EdgeUpdate(u, v, False)]))
+    assert not g.has_edge(u, v)
+    H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
+    for h, href in zip(eng.host_H(), H_ref):
+        np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)
+
+
 def test_device_engine_3layer():
     wl, g, params, state = _setup("gs-s", n_layers=3)
     eng = DeviceEngine(wl, params, g, state, min_bucket=16)
@@ -157,11 +173,12 @@ def test_overflow_commits_nothing(name):
     if eng.monotonic:
         new_state, final, ovf, sizes, _stats = propagate_monotonic_donated(
             wl, eng.n, caps, eng.params, eng.state,
-            eng.out_mirror.device(), eng.in_mirror.device(), dev_batch)
+            eng.out_mirror.device(), eng.in_mirror.device(), dev_batch,
+            interpret=eng.interpret)
     else:
         new_state, final, ovf, sizes = propagate_donated(
             wl, eng.n, caps, eng.params, eng.state,
-            eng.out_mirror.device(), dev_batch)
+            eng.out_mirror.device(), dev_batch, interpret=eng.interpret)
     assert bool(ovf), "tiny caps should overflow"
     for l, h in enumerate(new_state.H):
         np.testing.assert_array_equal(np.asarray(h), before["H"][l])
@@ -242,6 +259,31 @@ def test_device_k_maintained_without_host_reupload():
     for b in _stream(g, rng, n_batches=8):
         eng.apply_batch(b)
     np.testing.assert_allclose(np.array(eng.state.k), g.in_degree)
+    H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
+    for h, href in zip(eng.host_H(), H_ref):
+        np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)
+
+
+@pytest.mark.parametrize("name", ["gs-max", "gc-min"])
+def test_accelerator_lowerings_match_oracle(name):
+    """The two lowerings an accelerator takes — pair-flattened element
+    gathers for shrunk (row, dim) cells, and the device-side commit-row
+    gather that feeds the serving snapshot — steered on the CPU: the
+    state must stay oracle-exact and every logged commit must equal the
+    committed final-layer rows."""
+    wl, g, params, state = _setup(name)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=16)
+    eng.interpret = False        # what interpret_mode() gives on a TPU
+    eng._host_backend = False    # what a device-memory backend gives
+    eng.enable_commit_log()
+    shrinks = 0
+    for b in _stream(g, np.random.default_rng(19), n_batches=8):
+        eng.apply_batch(b)
+        shrinks += eng.last_shrink_events
+        H_last = eng.host_H()[-1]
+        for _idx, aff, rows in eng.drain_commits():
+            np.testing.assert_array_equal(rows, H_last[aff])
+    assert shrinks > 0, "the stream never exercised the shrink pull"
     H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
     for h, href in zip(eng.host_H(), H_ref):
         np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)

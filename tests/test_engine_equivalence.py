@@ -78,6 +78,30 @@ def test_feature_update(name, engine_cls):
 
 
 @pytest.mark.parametrize("name", WORKLOAD_NAMES)
+@pytest.mark.parametrize("engine_cls", [RippleEngine, RecomputeEngine])
+def test_add_then_delete_in_one_batch(name, engine_cls):
+    """A batch that adds a new edge and deletes it again leaves the state
+    as it was; a re-add after that delete lands."""
+    wl, g, x, params, state = _setup(name)
+    eng = engine_cls(wl, params_to_numpy(params), g, state)
+    u, v = 0, 1
+    while g.has_edge(u, v) or u == v:
+        v += 1
+    before = [h.copy() for h in state.H]
+    eng.apply_batch(UpdateBatch(edges=[EdgeUpdate(u, v, True, 0.5),
+                                       EdgeUpdate(u, v, True, 0.9),
+                                       EdgeUpdate(u, v, False)]))
+    assert not g.has_edge(u, v)
+    for h, h0 in zip(state.H, before):
+        np.testing.assert_allclose(h, h0, atol=ATOL, rtol=RTOL)
+    eng.apply_batch(UpdateBatch(edges=[EdgeUpdate(u, v, True, 0.5),
+                                       EdgeUpdate(u, v, False),
+                                       EdgeUpdate(u, v, True, 0.8)]))
+    assert g.has_edge(u, v)
+    _assert_state_matches(state, _oracle(wl, params, g, state.H[0]))
+
+
+@pytest.mark.parametrize("name", WORKLOAD_NAMES)
 @pytest.mark.parametrize("n_layers", [2, 3])
 def test_mixed_batches_sequence(name, n_layers):
     """Many consecutive mixed batches drift-free vs the oracle."""

@@ -24,7 +24,7 @@ def test_segment_mm(n, m, d, blk, dtype):
     from repro.kernels.segment_mm.ref import segment_mm_ref
     src, dst, w = erdos_renyi(n, m, seed=1, weighted=True)
     x = jnp.asarray(RNG.normal(size=(n, d)), dtype)
-    out = segment_mm(src, dst, w, x, n, blk=blk)
+    out = segment_mm(src, dst, w, x, n, blk=blk, interpret=True)
     ref = segment_mm_ref(jnp.asarray(src), jnp.asarray(dst),
                          jnp.asarray(w).astype(dtype), x, n)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -43,7 +43,8 @@ def test_delta_apply(R, Din, Dout, mean, relu):
     k = jnp.asarray(RNG.integers(0, 6, size=R), jnp.float32)
     W = jnp.asarray(RNG.normal(size=(Din, Dout)), jnp.float32)
     b = jnp.asarray(RNG.normal(size=Dout), jnp.float32)
-    Sn, h = delta_apply(S, M, k, W, b, mean=mean, relu=relu)
+    Sn, h = delta_apply(S, M, k, W, b, mean=mean, relu=relu,
+                        interpret=True)
     Sr, hr = delta_apply_ref(S, M, k, W, b, mean=mean, relu=relu)
     np.testing.assert_allclose(Sn, Sr, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(h, hr, atol=1e-4, rtol=1e-4)
@@ -66,7 +67,8 @@ def test_extremum_apply(R, Din, Dout, maximize, relu):
     M = M.at[jnp.asarray(RNG.choice(R, size=R // 4, replace=False))].set(ident)
     W = jnp.asarray(RNG.normal(size=(Din, Dout)), jnp.float32)
     b = jnp.asarray(RNG.normal(size=Dout), jnp.float32)
-    Sn, h = extremum_apply(S, M, W, b, maximize=maximize, relu=relu)
+    Sn, h = extremum_apply(S, M, W, b, maximize=maximize, relu=relu,
+                           interpret=True)
     Sr, hr = extremum_apply_ref(S, M, W, b, maximize=maximize, relu=relu)
     np.testing.assert_array_equal(np.asarray(Sn), np.asarray(Sr))
     np.testing.assert_allclose(h, hr, atol=1e-4, rtol=1e-4)
@@ -91,7 +93,7 @@ def test_extremum_apply_masked(R, Din, Dout, maximize):
     W = jnp.asarray(RNG.normal(size=(Din, Dout)), jnp.float32)
     b = jnp.asarray(RNG.normal(size=Dout), jnp.float32)
     Sn, h = extremum_apply(S, M, W, b, reagg=RG, mask=mask,
-                           maximize=maximize, relu=True)
+                           maximize=maximize, relu=True, interpret=True)
     Sr, hr = extremum_apply_ref(S, M, W, b, reagg=RG, mask=mask,
                                 maximize=maximize, relu=True)
     np.testing.assert_array_equal(np.asarray(Sn), np.asarray(Sr))
@@ -116,7 +118,8 @@ def test_mlp_apply(R, Din, Dh, Dout, mean, relu):
     b1 = jnp.asarray(RNG.normal(size=Dh), jnp.float32)
     W2 = jnp.asarray(RNG.normal(size=(Dh, Dout)), jnp.float32)
     b2 = jnp.asarray(RNG.normal(size=Dout), jnp.float32)
-    Sn, h = mlp_apply(S, M, hp, k, eps, W1, b1, W2, b2, mean=mean, relu=relu)
+    Sn, h = mlp_apply(S, M, hp, k, eps, W1, b1, W2, b2, mean=mean, relu=relu,
+                      interpret=True)
     Sr, hr = mlp_apply_ref(S, M, hp, k, eps, W1, b1, W2, b2,
                            mean=mean, relu=relu)
     np.testing.assert_allclose(Sn, Sr, atol=1e-5, rtol=1e-5)
@@ -132,18 +135,20 @@ def test_embedding_bag(V, B, hot, d, dtype):
     from repro.kernels.embedding_bag.ref import embedding_bag_ref
     table = jnp.asarray(RNG.normal(size=(V, d)), dtype)
     idx = jnp.asarray(RNG.integers(0, V, size=(B, hot)), jnp.int32)
-    out = embedding_bag_kernel(table, idx)
+    out = embedding_bag_kernel(table, idx, interpret=True)
     ref = embedding_bag_ref(table, idx)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("V,R,hot,d", [(64, 16, 8, 16), (200, 48, 12, 32)])
+@pytest.mark.parametrize("V,R,hot,d", [(64, 16, 8, 16), (200, 48, 12, 32),
+                                       (200, 40, 1000, 16)])
 def test_embedding_bag_engine_pattern(V, R, hot, d):
     """The bounded device engine's gather shape: a [R, hot] in-neighbor id
     rectangle padded with sentinel V pointing at a zero row appended to the
     table — the kernel's bag sum must equal the masked dense sum (this is
-    gp-m's per-row first-moment gather under ``use_pallas``)."""
+    gp-m's per-row first-moment gather under ``use_pallas``).  The last
+    case holds more indices than one SMEM prefetch, so it runs chunked."""
     from repro.kernels.embedding_bag import embedding_bag_pallas
     table = jnp.asarray(RNG.normal(size=(V, d)), jnp.float32)
     padded = jnp.concatenate([table, jnp.zeros((1, d), jnp.float32)])
@@ -170,7 +175,7 @@ def test_flash_attention(B, S, H, Hkv, Dh, bq, bkv, dtype):
     q = jnp.asarray(RNG.normal(size=(B, S, H, Dh)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, S, Hkv, Dh)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, S, Hkv, Dh)), dtype)
-    out = flash_attention(q, k, v, bq=bq, bkv=bkv)
+    out = flash_attention(q, k, v, bq=bq, bkv=bkv, interpret=True)
     ref = flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -188,6 +193,6 @@ def test_flash_matches_model_attention():
     q = jnp.asarray(RNG.normal(size=(2, 64, 4, 16)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(2, 64, 2, 16)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(2, 64, 2, 16)), jnp.float32)
-    a = flash_attention(q, k, v, bq=32, bkv=32)
+    a = flash_attention(q, k, v, bq=32, bkv=32, interpret=True)
     b = causal_attention(q, k, v, cfg)
     np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4)
