@@ -34,6 +34,7 @@ from repro.core.graph import DynamicGraph, UpdateBatch
 from repro.core.state import InferenceState, params_to_numpy
 from repro.core.vertexwise import VertexWiseEngine
 from repro.core.workloads import Workload
+from repro.utils.trace import span
 
 from .registry import EngineOption, UpdateResult, register_engine
 
@@ -179,7 +180,9 @@ class DeviceAdapter:
         if not self._async:
             # the resolve above already blocked on the overflow flag; this
             # pins wall_seconds to the fully-materialized state
-            jax.block_until_ready((self._impl.state.H, self._impl.state.S))
+            with span("ripple.engine.device_wait"):
+                jax.block_until_ready((self._impl.state.H,
+                                       self._impl.state.S))
         return UpdateResult(affected=affected,
                             wall_seconds=time.perf_counter() - t0,
                             affected_per_hop=[int(affected.size)],

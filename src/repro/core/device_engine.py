@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import interpret_mode
+from repro.utils.trace import span
 
 from .aggregators import (MAX, certified_error_bound, deferral_budgets,
                           jnp_segment_extremum)
@@ -97,6 +98,24 @@ def _mirror_scatter(col, w, length, ints, slot_w, *, kb: int):
     return col, w, length
 
 
+class ShapeMisses(dict):
+    """First sightings of a static jit key, counted by call site.
+
+    Each is a trace plus a compile (or compile-cache load) at that site:
+    ``propagate`` keys on the caps, the batch width and the mirror pools,
+    ``mirror_scatter`` on ``(pool, kb, rb)`` and ``commit_gather`` on the
+    padded index size. The check is one set lookup per call."""
+
+    def __init__(self):
+        super().__init__(propagate=0, mirror_scatter=0, commit_gather=0)
+        self._seen: set = set()
+
+    def note(self, site: str, key) -> None:
+        if (site, key) not in self._seen:
+            self._seen.add((site, key))
+            self[site] += 1
+
+
 class DeviceCSRMirror:
     """Persistent device-resident slack-pool CSR of one adjacency half.
 
@@ -107,41 +126,47 @@ class DeviceCSRMirror:
     donated device scatter, O(sum of touched row degrees) host→device
     traffic.  A full pool upload happens exactly once at construction and
     again only when a row outgrows its slack (``rebuilds``); the counters
-    let tests assert the no-O(E)-per-batch contract.
+    let tests assert the no-O(E)-per-batch contract. ``shapes`` receives
+    each refresh's scatter key (shared by the mirrors of one engine, as
+    the jit cache is).
     """
 
-    def __init__(self, half, *, min_pool: int = 1024):
+    def __init__(self, half, *, min_pool: int = 1024,
+                 shapes: ShapeMisses | None = None):
         from repro.utils import next_bucket
         self._next_bucket = next_bucket
         self.half = half            # backing host _AdjHalf (authoritative)
         self.min_pool = min_pool
+        self.shapes = ShapeMisses() if shapes is None else shapes
         self.uploads = 0            # full-pool uploads (init + rebuilds)
         self.rebuilds = -1          # slack-overflow re-layouts
         self.row_refreshes = 0      # rows refreshed incrementally
         self._rebuild()
 
     def _rebuild(self) -> None:
-        n = self.half.n
-        deg = self.half.length.astype(np.int64)
-        cap = np.maximum((deg * _GROW).astype(np.int64) + _MIN_SLACK, deg)
-        start = np.zeros(n, dtype=np.int64)
-        if n:
-            np.cumsum(cap[:-1], out=start[1:])
-        pool = self._next_bucket(int(start[-1] + cap[-1]) if n else 1,
-                                 minimum=self.min_pool)
-        col = np.full(pool, -1, dtype=np.int32)
-        w = np.zeros(pool, dtype=np.float32)
-        if deg.sum():
-            src_idx = flat_row_indices(self.half.start, deg)
-            dst_idx = flat_row_indices(start, deg)
-            col[dst_idx] = self.half.col[src_idx]
-            w[dst_idx] = self.half.w[src_idx]
-        self._start_h, self._cap_h = start, cap
-        self.pool = pool
-        self.col = jnp.asarray(col)
-        self.w = jnp.asarray(w)
-        self.start = jnp.asarray(start, dtype=jnp.int32)
-        self.length = jnp.asarray(deg, dtype=jnp.int32)
+        with span("ripple.mirror.rebuild"):
+            n = self.half.n
+            deg = self.half.length.astype(np.int64)
+            cap = np.maximum((deg * _GROW).astype(np.int64) + _MIN_SLACK,
+                             deg)
+            start = np.zeros(n, dtype=np.int64)
+            if n:
+                np.cumsum(cap[:-1], out=start[1:])
+            pool = self._next_bucket(int(start[-1] + cap[-1]) if n else 1,
+                                     minimum=self.min_pool)
+            col = np.full(pool, -1, dtype=np.int32)
+            w = np.zeros(pool, dtype=np.float32)
+            if deg.sum():
+                src_idx = flat_row_indices(self.half.start, deg)
+                dst_idx = flat_row_indices(start, deg)
+                col[dst_idx] = self.half.col[src_idx]
+                w[dst_idx] = self.half.w[src_idx]
+            self._start_h, self._cap_h = start, cap
+            self.pool = pool
+            self.col = jnp.asarray(col)
+            self.w = jnp.asarray(w)
+            self.start = jnp.asarray(start, dtype=jnp.int32)
+            self.length = jnp.asarray(deg, dtype=jnp.int32)
         self.uploads += 1
         self.rebuilds += 1
 
@@ -152,23 +177,25 @@ class DeviceCSRMirror:
         rows = np.asarray(rows, dtype=np.int64)
         if rows.size == 0:
             return
-        deg = self.half.length[rows]
-        if np.any(deg > self._cap_h[rows]):
-            self._rebuild()         # some row outgrew its slack
-            return
-        src_idx = flat_row_indices(self.half.start[rows], deg)
-        dst_idx = flat_row_indices(self._start_h[rows], deg)
-        kb = self._next_bucket(max(int(dst_idx.size), 1), minimum=64)
-        rb = self._next_bucket(int(rows.size), minimum=64)
-        n = self.half.n
-        ints = np.concatenate([
-            pad_to(dst_idx, kb, fill=self.pool),
-            pad_to(self.half.col[src_idx], kb),
-            pad_to(rows, rb, fill=n),
-            pad_to(deg, rb)]).astype(np.int32)
-        self.col, self.w, self.length = _mirror_scatter(
-            self.col, self.w, self.length, jnp.asarray(ints),
-            jnp.asarray(pad_to(self.half.w[src_idx], kb)), kb=kb)
+        with span("ripple.mirror.refresh"):
+            deg = self.half.length[rows]
+            if np.any(deg > self._cap_h[rows]):
+                self._rebuild()         # some row outgrew its slack
+                return
+            src_idx = flat_row_indices(self.half.start[rows], deg)
+            dst_idx = flat_row_indices(self._start_h[rows], deg)
+            kb = self._next_bucket(max(int(dst_idx.size), 1), minimum=64)
+            rb = self._next_bucket(int(rows.size), minimum=64)
+            n = self.half.n
+            ints = np.concatenate([
+                pad_to(dst_idx, kb, fill=self.pool),
+                pad_to(self.half.col[src_idx], kb),
+                pad_to(rows, rb, fill=n),
+                pad_to(deg, rb)]).astype(np.int32)
+            self.shapes.note("mirror_scatter", (self.pool, kb, rb))
+            self.col, self.w, self.length = _mirror_scatter(
+                self.col, self.w, self.length, jnp.asarray(ints),
+                jnp.asarray(pad_to(self.half.w[src_idx], kb)), kb=kb)
         self.row_refreshes += int(rows.size)
 
     def device(self) -> DeviceCSR:
@@ -955,6 +982,13 @@ propagate_bounded_donated = jax.jit(_propagate_bounded_impl,
                                     donate_argnames=("state",))
 
 
+def _overflowed(flag: jax.Array) -> bool:
+    """The propagate's overflow flag, which blocks until the device has
+    run the step."""
+    with span("ripple.engine.device_wait"):
+        return bool(flag)
+
+
 class DeviceEngine:
     """Host driver around the jitted propagation with a warm bucket ladder.
 
@@ -1024,8 +1058,10 @@ class DeviceEngine:
         # host-memory backend: device arrays are host arrays, so the
         # serving commit log indexes them instead of gathering on device
         self._host_backend = jax.default_backend() == "cpu"
-        self.out_mirror = DeviceCSRMirror(graph.out)
-        self.in_mirror = DeviceCSRMirror(graph.inn) \
+        self.shape_misses = ShapeMisses()
+        self.out_mirror = DeviceCSRMirror(graph.out,
+                                          shapes=self.shape_misses)
+        self.in_mirror = DeviceCSRMirror(graph.inn, shapes=self.shape_misses) \
             if (self.monotonic or self.bounded) else None
         self._bucket = min_bucket
         self._rung = 0          # transient retry boost (0 once sizes known)
@@ -1182,40 +1218,46 @@ class DeviceEngine:
         mirrors (that happens after the previous batch resolves, so a retry
         of batch t-1 still sees t-1's adjacency)."""
         from repro.utils import pad_to
-        n = self.n
-        d0 = int(self.state.H[0].shape[1])
-        adds, dels = self.graph.apply_topology(batch.edges)
-        if self.bounded and n:
-            self._kmax = max(self._kmax, float(self.graph.in_degree.max()))
-        fa = np.array([f.vertex for f in batch.features], dtype=np.int32)
-        fx = (np.stack([f.value for f in batch.features]).astype(np.float32)
-              if batch.features else np.zeros((0, d0), np.float32))
-        # last-writer-wins for duplicate feature updates
-        if fa.size:
-            uniq, last = np.unique(fa[::-1], return_index=True)
-            fa, fx = uniq.astype(np.int32), fx[::-1][last]
-        need = max(len(fa), len(adds), len(dels), 1)
-        if need > self._bucket:
-            self._bucket = self._next_bucket(need, minimum=self.min_bucket)
-        cap = self._bucket
-        ints = np.full((5, cap), n, dtype=np.int32)
-        ws = np.zeros((2, cap), dtype=np.float32)
-        ints[0, :fa.size] = fa
-        for row, vals in ((1, [e.src for e in adds]),
-                          (2, [e.dst for e in adds]),
-                          (3, [e.src for e in dels]),
-                          (4, [e.dst for e in dels])):
-            ints[row, :len(vals)] = vals
-        ws[0, :len(adds)] = [e.weight for e in adds]
-        ws[1, :len(dels)] = [e.weight for e in dels]
-        dev_batch = BatchDev(ints=jnp.asarray(ints), ws=jnp.asarray(ws),
-                             feat_val=jnp.asarray(pad_to(fx, cap)))
-        touched = adds + dels
-        out_rows = np.unique(np.array([e.src for e in touched], np.int64)) \
-            if touched else np.empty(0, np.int64)
-        in_rows = np.unique(np.array([e.dst for e in touched], np.int64)) \
-            if touched and self.in_mirror is not None \
-            else np.empty(0, np.int64)
+        with span("ripple.engine.route"):
+            n = self.n
+            d0 = int(self.state.H[0].shape[1])
+            adds, dels = self.graph.apply_topology(batch.edges)
+            if self.bounded and n:
+                self._kmax = max(self._kmax,
+                                 float(self.graph.in_degree.max()))
+            fa = np.array([f.vertex for f in batch.features], dtype=np.int32)
+            fx = (np.stack([f.value for f in batch.features])
+                  .astype(np.float32)
+                  if batch.features else np.zeros((0, d0), np.float32))
+            # last-writer-wins for duplicate feature updates
+            if fa.size:
+                uniq, last = np.unique(fa[::-1], return_index=True)
+                fa, fx = uniq.astype(np.int32), fx[::-1][last]
+            need = max(len(fa), len(adds), len(dels), 1)
+            if need > self._bucket:
+                self._bucket = self._next_bucket(need,
+                                                 minimum=self.min_bucket)
+            cap = self._bucket
+            ints = np.full((5, cap), n, dtype=np.int32)
+            ws = np.zeros((2, cap), dtype=np.float32)
+            ints[0, :fa.size] = fa
+            for row, vals in ((1, [e.src for e in adds]),
+                              (2, [e.dst for e in adds]),
+                              (3, [e.src for e in dels]),
+                              (4, [e.dst for e in dels])):
+                ints[row, :len(vals)] = vals
+            ws[0, :len(adds)] = [e.weight for e in adds]
+            ws[1, :len(dels)] = [e.weight for e in dels]
+            dev_batch = BatchDev(ints=jnp.asarray(ints), ws=jnp.asarray(ws),
+                                 feat_val=jnp.asarray(pad_to(fx, cap)))
+            touched = adds + dels
+            out_rows = np.unique(np.array([e.src for e in touched],
+                                          np.int64)) \
+                if touched else np.empty(0, np.int64)
+            in_rows = np.unique(np.array([e.dst for e in touched],
+                                         np.int64)) \
+                if touched and self.in_mirror is not None \
+                else np.empty(0, np.int64)
         return dev_batch, out_rows, in_rows
 
     # -- dispatch / resolve ------------------------------------------------
@@ -1236,6 +1278,9 @@ class DeviceEngine:
             head + (dev_batch,)
 
     def _run(self, dev_batch: BatchDev, caps: tuple):
+        self.shape_misses.note("propagate", (
+            caps, dev_batch.ints.shape[1], self.out_mirror.pool,
+            self.in_mirror.pool if self.in_mirror is not None else 0))
         fn, args = self._call(dev_batch, caps)
         out = fn(*args, pallas=self.use_pallas, interpret=self.interpret)
         return out if len(out) == 5 else (*out, None)
@@ -1250,8 +1295,10 @@ class DeviceEngine:
 
     def _dispatch(self, dev_batch: BatchDev) -> None:
         assert self._pending is None
-        caps = self._caps(self._rung)
-        new_state, final, overflow, sizes, stats = self._run(dev_batch, caps)
+        with span("ripple.engine.dispatch"):
+            caps = self._caps(self._rung)
+            new_state, final, overflow, sizes, stats = self._run(dev_batch,
+                                                                 caps)
         # optimistic commit: on overflow the gated writes all dropped, so
         # these buffers hold the pre-batch values and the retry is safe
         self.state = new_state
@@ -1266,7 +1313,7 @@ class DeviceEngine:
             return self._last_affected
         overflow, final, sizes, stats, dev_batch, caps, k_check = \
             self._pending
-        while bool(overflow):
+        while _overflowed(overflow):
             self.retries += 1
             # the failed attempt reported what it actually needed; aim the
             # retry straight at fitting caps (truncated attempts may still
@@ -1285,13 +1332,16 @@ class DeviceEngine:
                                        "overflowing — graph inconsistency?")
             else:
                 self._rung = 0
-            new_state, final, overflow, sizes, stats = self._run(dev_batch,
-                                                                 new_caps)
+            with span("ripple.engine.retry"):
+                new_state, final, overflow, sizes, stats = self._run(
+                    dev_batch, new_caps)
             caps = new_caps
             self.state = new_state
         self._note_sizes(sizes)
         self._rung = 0
-        f = np.asarray(final)
+        with span("ripple.engine.device_wait"):
+            f = np.asarray(final)
+            stats = None if stats is None else jax.device_get(stats)
         self._last_affected = f[f < self.n].astype(np.int64)
         if stats is not None:
             if self.bounded:
@@ -1321,25 +1371,27 @@ class DeviceEngine:
             # host before the *next* dispatch can donate these buffers away.
             # The gather index is padded to a power-of-two bucket so the jit
             # compiles O(log n) programs, not one per distinct frontier size
-            self._commits += 1
-            aff = self._last_affected
-            if not aff.size:
-                rows = np.zeros((0, int(self.state.H[-1].shape[1])),
-                                np.float32)
-            elif self._host_backend:
-                # host backend: np.asarray is ~zero-copy, a device gather
-                # dispatch costs ~100x more than indexing on the host
-                rows = np.asarray(self.state.H[-1])[aff]
-            else:
-                # accelerator: gather only the frontier rows, padding the
-                # index to a power-of-two bucket so the jit compiles
-                # O(log n) programs, not one per distinct frontier size
-                cap = self._next_bucket(aff.size)
-                idx = np.full(cap, aff[0], dtype=np.int64)
-                idx[:aff.size] = aff
-                rows = np.asarray(self.state.H[-1][jnp.asarray(idx)])
-                rows = rows[:aff.size]
-            self._commit_log.append((self._commits, aff.copy(), rows))
+            with span("ripple.engine.commit_gather"):
+                self._commits += 1
+                aff = self._last_affected
+                if not aff.size:
+                    rows = np.zeros((0, int(self.state.H[-1].shape[1])),
+                                    np.float32)
+                elif self._host_backend:
+                    # host backend: np.asarray is ~zero-copy, a device gather
+                    # dispatch costs ~100x more than indexing on the host
+                    rows = np.asarray(self.state.H[-1])[aff]
+                else:
+                    # accelerator: gather only the frontier rows, padding the
+                    # index to a power-of-two bucket so the jit compiles
+                    # O(log n) programs, not one per distinct frontier size
+                    cap = self._next_bucket(aff.size)
+                    self.shape_misses.note("commit_gather", cap)
+                    idx = np.full(cap, aff[0], dtype=np.int64)
+                    idx[:aff.size] = aff
+                    rows = np.asarray(self.state.H[-1][jnp.asarray(idx)])
+                    rows = rows[:aff.size]
+                self._commit_log.append((self._commits, aff.copy(), rows))
         self._pending = None
         return self._last_affected
 

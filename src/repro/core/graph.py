@@ -19,6 +19,8 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
+from repro.utils.trace import span
+
 _GROW = 1.5  # row slack growth factor
 _MIN_SLACK = 4
 
@@ -216,13 +218,14 @@ class DynamicGraph:
         """
         adds: list[EdgeUpdate] = []
         dels: list[EdgeUpdate] = []
-        for e in self._net_updates(edges):
-            if e.add:
-                self.add_edge(e.src, e.dst, e.weight)
-                adds.append(e)
-            else:
-                w = self.delete_edge(e.src, e.dst)
-                dels.append(EdgeUpdate(e.src, e.dst, False, w))
+        with span("ripple.graph.topology"):
+            for e in self._net_updates(edges):
+                if e.add:
+                    self.add_edge(e.src, e.dst, e.weight)
+                    adds.append(e)
+                else:
+                    w = self.delete_edge(e.src, e.dst)
+                    dels.append(EdgeUpdate(e.src, e.dst, False, w))
         return adds, dels
 
     def _net_updates(self, edges: Sequence[EdgeUpdate]) -> list[EdgeUpdate]:

@@ -53,6 +53,7 @@ from typing import NamedTuple
 import numpy as np
 
 from repro.core.graph import EdgeUpdate, UpdateBatch
+from repro.utils import trace
 
 from .scheduler import AdmissionController, ControllerConfig, LatencyModel
 from .tenants import (AdmissionError, StaleReadError, Tenant, TenantConfig)
@@ -286,19 +287,22 @@ class GraphServer:
     # the worker applies one micro-batch per _step; queue lock is dropped
     # before the engine is touched
     def _step(self) -> bool:
-        with self._qcv:
-            if not self._qtotal:
-                return False
-            bs = self.controller.next_batch_size(self._qtotal)
-            chunk = self._pop_weighted(min(bs, self._qtotal))
-            self._busy += 1
-            self._qcv.notify_all()
-        try:
-            self._apply_chunk(chunk)
-        finally:
-            with self._qcv:
-                self._busy -= 1
+        # spans: one micro_batch root per step, tagged with the index the
+        # batch takes in batch_sizes (see repro.utils.trace)
+        with trace.micro_batch(len(self.batch_sizes)):
+            with trace.span("ripple.serve.take"), self._qcv:
+                if not self._qtotal:
+                    return False
+                bs = self.controller.next_batch_size(self._qtotal)
+                chunk = self._pop_weighted(min(bs, self._qtotal))
+                self._busy += 1
                 self._qcv.notify_all()
+            try:
+                self._apply_chunk(chunk)
+            finally:
+                with self._qcv:
+                    self._busy -= 1
+                    self._qcv.notify_all()
         return True
 
     def _pop_weighted(self, n: int) -> list[_Submitted]:
@@ -338,12 +342,13 @@ class GraphServer:
                 self._scv.notify_all()
 
     def _apply_chunk(self, chunk: list[_Submitted]) -> None:
-        batch = UpdateBatch()
-        meta: dict[Tenant, int] = {}
-        for s in chunk:
-            (batch.edges if isinstance(s.update, EdgeUpdate)
-             else batch.features).append(s.update)
-            meta[s.tenant] = max(meta.get(s.tenant, 0), s.seq)
+        with trace.span("ripple.serve.take"):
+            batch = UpdateBatch()
+            meta: dict[Tenant, int] = {}
+            for s in chunk:
+                (batch.edges if isinstance(s.update, EdgeUpdate)
+                 else batch.features).append(s.update)
+                meta[s.tenant] = max(meta.get(s.tenant, 0), s.seq)
         with self._elock:
             t0 = time.perf_counter()
             if self._t_first_apply is None:
@@ -408,7 +413,7 @@ class GraphServer:
         """Fold one committed batch's final-layer patch into the snapshot
         and advance every covered tenant's committed sequence."""
         t_now = time.perf_counter()
-        with self._scv:
+        with trace.span("ripple.serve.publish"), self._scv:
             meta, n_updates = self._inflight.popleft() if self._inflight \
                 else ({}, 0)
             self.published_updates += n_updates

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.api import InferenceSession, SessionConfig
+from repro.core.graph import EdgeUpdate, FeatureUpdate
 from repro.serve import (AdmissionError, ClosedLoopLoad, GraphServer,
                          LatencyModel, OpenLoopLoad, StaleReadError,
                          TenantConfig, split_stream, tenant_shares)
@@ -365,3 +366,87 @@ def test_weighted_deficit_tenant_share():
     m = srv.metrics()["tenants"]
     assert m["heavy"]["committed"] == 100
     assert m["light"]["committed"] == 100
+
+
+# -- spans and counters of the served device path -----------------------------
+_SPANS = ("ripple.serve.micro_batch", "ripple.serve.take",
+          "ripple.graph.topology", "ripple.engine.route",
+          "ripple.mirror.refresh", "ripple.mirror.rebuild",
+          "ripple.engine.dispatch", "ripple.engine.device_wait",
+          "ripple.engine.retry", "ripple.engine.commit_gather",
+          "ripple.serve.publish")
+
+
+def _program_spans(trace_dir):
+    """{thread: [(start_ns, end_ns, name, batch)]} of the ripple.* spans in
+    the profile written under ``trace_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for k, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("ripple."):
+                    out.setdefault((plane.name, k), []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns, e.name,
+                         dict(e.stats).get("batch")))
+    return out
+
+
+def test_device_path_spans_and_shape_misses(tmp_path):
+    """Under a profiler session the served device path records every span
+    on the worker thread, each inside the micro-batch root that carries
+    its batch number; a repeated refresh shape is no new mirror key, a
+    larger one is, and a row outgrowing its slack is a rebuild."""
+    import jax
+
+    s = _session("device", engine_options={"min_bucket": 4})
+    eng = s.engine.impl
+    g = s.graph
+    srv = GraphServer(s, tenants=["a"], max_batch=24).start()
+
+    def new_edges(src, k):
+        return [EdgeUpdate(src, v, True, 1.0) for v in range(g.n)
+                if v != src and not g.has_edge(src, v)][:k]
+
+    def scatter_misses(updates):
+        before = eng.shape_misses["mirror_scatter"]
+        srv.submit("a", updates)
+        srv.drain()
+        return eng.shape_misses["mirror_scatter"] - before
+
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            # the first batch meets the geometric caps of min_bucket 4 and
+            # overflows; a fresh refresh shape is one miss
+            srv.submit("a", [FeatureUpdate(v, np.ones(8, np.float32))
+                             for v in range(8)])
+            srv.drain()
+            assert scatter_misses(new_edges(1, 1)) == 1
+            assert scatter_misses(new_edges(2, 1)) == 0
+            rebuilds = eng.out_mirror.rebuilds
+            # 8 adds outgrow vertex 0's slack (1.5 x degree + 4): a rebuild
+            # and no scatter; then a refresh of more than 64 slots
+            srv.submit("a", new_edges(0, 8))
+            srv.drain()
+            assert eng.out_mirror.rebuilds == rebuilds + 1
+            wide = [e for u in range(3, 23) for e in new_edges(u, 1)]
+            assert scatter_misses(wide) == 1
+    finally:
+        srv.stop()
+    assert eng.retries > 0
+    assert eng.shape_misses["propagate"] >= 2    # warm-up + the retry rung
+    by_thread = _program_spans(str(tmp_path))
+    assert len(by_thread) == 1, "program spans off the worker thread"
+    spans, = by_thread.values()
+    assert {n for _, _, n, _ in spans} == set(_SPANS)
+    roots = {b: (s0, e0) for s0, e0, n, b in spans
+             if n == "ripple.serve.micro_batch"}
+    assert len(roots) == len(srv.batch_sizes)
+    for s0, e0, n, b in spans:
+        assert b in roots and roots[b][0] <= s0 <= e0 <= roots[b][1], n
