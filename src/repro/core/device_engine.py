@@ -59,7 +59,8 @@ from repro.utils.trace import span
 
 from .aggregators import (MAX, certified_error_bound, deferral_budgets,
                           jnp_segment_extremum)
-from .graph import _GROW, _MIN_SLACK, DynamicGraph, flat_row_indices
+from .graph import (_GROW, _MIN_SLACK, DynamicGraph, edge_columns,
+                    flat_row_indices)
 from .workloads import Workload, matmul_f32
 
 
@@ -1221,7 +1222,8 @@ class DeviceEngine:
         with span("ripple.engine.route"):
             n = self.n
             d0 = int(self.state.H[0].shape[1])
-            adds, dels = self.graph.apply_topology(batch.edges)
+            (a_src, a_dst, a_w), (d_src, d_dst, d_w) = \
+                self.graph.apply_edges(*edge_columns(batch.edges))
             if self.bounded and n:
                 self._kmax = max(self._kmax,
                                  float(self.graph.in_degree.max()))
@@ -1233,7 +1235,7 @@ class DeviceEngine:
             if fa.size:
                 uniq, last = np.unique(fa[::-1], return_index=True)
                 fa, fx = uniq.astype(np.int32), fx[::-1][last]
-            need = max(len(fa), len(adds), len(dels), 1)
+            need = max(fa.size, a_src.size, d_src.size, 1)
             if need > self._bucket:
                 self._bucket = self._next_bucket(need,
                                                  minimum=self.min_bucket)
@@ -1241,23 +1243,15 @@ class DeviceEngine:
             ints = np.full((5, cap), n, dtype=np.int32)
             ws = np.zeros((2, cap), dtype=np.float32)
             ints[0, :fa.size] = fa
-            for row, vals in ((1, [e.src for e in adds]),
-                              (2, [e.dst for e in adds]),
-                              (3, [e.src for e in dels]),
-                              (4, [e.dst for e in dels])):
-                ints[row, :len(vals)] = vals
-            ws[0, :len(adds)] = [e.weight for e in adds]
-            ws[1, :len(dels)] = [e.weight for e in dels]
+            for row, vals in enumerate((a_src, a_dst, d_src, d_dst), 1):
+                ints[row, :vals.size] = vals
+            ws[0, :a_w.size] = a_w
+            ws[1, :d_w.size] = d_w
             dev_batch = BatchDev(ints=jnp.asarray(ints), ws=jnp.asarray(ws),
                                  feat_val=jnp.asarray(pad_to(fx, cap)))
-            touched = adds + dels
-            out_rows = np.unique(np.array([e.src for e in touched],
-                                          np.int64)) \
-                if touched else np.empty(0, np.int64)
-            in_rows = np.unique(np.array([e.dst for e in touched],
-                                         np.int64)) \
-                if touched and self.in_mirror is not None \
-                else np.empty(0, np.int64)
+            out_rows = np.unique(np.concatenate((a_src, d_src)))
+            in_rows = np.unique(np.concatenate((a_dst, d_dst))) \
+                if self.in_mirror is not None else np.empty(0, np.int64)
         return dev_batch, out_rows, in_rows
 
     # -- dispatch / resolve ------------------------------------------------

@@ -56,8 +56,8 @@ from repro.utils import next_bucket
 from .distributed import (DistBatch, DistCSR, make_monotonic_propagate,
                           make_rc_propagate, make_ripple_propagate,
                           tp_param_specs)
-from .graph import _GROW, _MIN_SLACK, DynamicGraph, UpdateBatch, \
-    flat_row_indices
+from .graph import (_GROW, _MIN_SLACK, DynamicGraph, EdgeUpdate,
+                    UpdateBatch, flat_row_indices)
 from .partition import Partitioning, ldg_partition
 from .state import InferenceState
 from .workloads import Workload
@@ -385,10 +385,11 @@ class DistEngine:
                   for e in adds]
         r_dels = [(int(relabel[e.src]), int(relabel[e.dst]), e.weight)
                   for e in dels]
-        for s, d, wt in r_adds:
-            self.g.add_edge(s, d, wt)
-        for s, d, _ in r_dels:
-            self.g.delete_edge(s, d)
+        # the relabeled graph takes the same net updates, deletes first:
+        # a batch may delete an edge and add it back
+        self.g.apply_topology([EdgeUpdate(s, d, False) for s, d, _ in r_dels]
+                              + [EdgeUpdate(s, d, True, wt)
+                                 for s, d, wt in r_adds])
         touched = r_adds + r_dels
         out_rows = np.unique([s for s, _, _ in touched]) if touched \
             else np.empty(0, np.int64)

@@ -26,6 +26,7 @@ import jax  # noqa: E402
 
 from repro.api import InferenceSession, SessionConfig, engine_names  # noqa: E402
 from repro.core import full_inference  # noqa: E402
+from repro.core.graph import EdgeUpdate, FeatureUpdate  # noqa: E402
 from repro.launch.mesh import make_local_mesh  # noqa: E402
 
 ATOL = 3e-3
@@ -227,6 +228,19 @@ def run_elastic_resize() -> None:
     print("OK elastic_resize 4 -> 2 partitions")
 
 
+def run_delete_then_readd() -> None:
+    """A batch that deletes an edge and adds it back leaves the edge in the
+    mesh engine's relabeled graph too, so a later update through it stays
+    exact."""
+    s = build("gc-s", "dist", {"mesh": make_local_mesh(4, 2)})
+    src, dst, _ = s.graph.coo()
+    u, v = int(src[0]), int(dst[0])
+    s.ingest([EdgeUpdate(u, v, False), EdgeUpdate(u, v, True)])
+    s.ingest([FeatureUpdate(u, np.full(8, 3.0, np.float32))])
+    assert_exact(s, "delete then re-add")
+    print("OK delete then re-add in one batch")
+
+
 if __name__ == "__main__":
     assert {"dist", "dist-rc"} <= set(engine_names())
     for mode in ("ripple", "rc"):
@@ -239,4 +253,5 @@ if __name__ == "__main__":
     run_swap_roundtrip()
     run_ckpt_geometry_change()
     run_elastic_resize()
+    run_delete_then_readd()
     print("ALL DIST OK")
