@@ -129,23 +129,27 @@ class DeviceCSRMirror:
     again only when a row outgrows its slack (``rebuilds``); the counters
     let tests assert the no-O(E)-per-batch contract. ``shapes`` receives
     each refresh's scatter key (shared by the mirrors of one engine, as
-    the jit cache is).
+    the jit cache is). Its work is timed under the spans
+    ``<span_prefix>refresh`` and ``<span_prefix>rebuild``.
     """
 
     def __init__(self, half, *, min_pool: int = 1024,
-                 shapes: ShapeMisses | None = None):
+                 shapes: ShapeMisses | None = None,
+                 span_prefix: str = "ripple.mirror."):
         from repro.utils import next_bucket
         self._next_bucket = next_bucket
         self.half = half            # backing host _AdjHalf (authoritative)
         self.min_pool = min_pool
         self.shapes = ShapeMisses() if shapes is None else shapes
+        self._span_refresh = span_prefix + "refresh"
+        self._span_rebuild = span_prefix + "rebuild"
         self.uploads = 0            # full-pool uploads (init + rebuilds)
         self.rebuilds = -1          # slack-overflow re-layouts
         self.row_refreshes = 0      # rows refreshed incrementally
         self._rebuild()
 
     def _rebuild(self) -> None:
-        with span("ripple.mirror.rebuild"):
+        with span(self._span_rebuild):
             n = self.half.n
             deg = self.half.length.astype(np.int64)
             cap = np.maximum((deg * _GROW).astype(np.int64) + _MIN_SLACK,
@@ -178,7 +182,7 @@ class DeviceCSRMirror:
         rows = np.asarray(rows, dtype=np.int64)
         if rows.size == 0:
             return
-        with span("ripple.mirror.refresh"):
+        with span(self._span_refresh):
             deg = self.half.length[rows]
             if np.any(deg > self._cap_h[rows]):
                 self._rebuild()         # some row outgrew its slack
@@ -990,6 +994,12 @@ def _overflowed(flag: jax.Array) -> bool:
         return bool(flag)
 
 
+# the cap channels of each algebra family's propagate, in ``caps`` order
+_CHANNELS = {"invertible": ("rows", "edges"),
+             "monotonic": ("rows", "edges", "pulls", "pairs"),
+             "bounded": ("rows", "edges", "pulls", "indeg")}
+
+
 class DeviceEngine:
     """Host driver around the jitted propagation with a warm bucket ladder.
 
@@ -1062,7 +1072,8 @@ class DeviceEngine:
         self.shape_misses = ShapeMisses()
         self.out_mirror = DeviceCSRMirror(graph.out,
                                           shapes=self.shape_misses)
-        self.in_mirror = DeviceCSRMirror(graph.inn, shapes=self.shape_misses) \
+        self.in_mirror = DeviceCSRMirror(graph.inn, shapes=self.shape_misses,
+                                         span_prefix="ripple.mirror.in_") \
             if (self.monotonic or self.bounded) else None
         self._bucket = min_bucket
         self._rung = 0          # transient retry boost (0 once sizes known)
@@ -1070,6 +1081,10 @@ class DeviceEngine:
         #                         invertible, [L, 4] (r, e, p, pd) monotonic
         self._notes = 0         # high-water adoptions (settle-phase counter)
         self.retries = 0        # overflow retries across the stream
+        # the same retries by the cap channel that overflowed (a retry
+        # whose attempt overflowed two channels counts under both)
+        self.retries_by_channel = dict.fromkeys(
+            _CHANNELS[workload.agg.algebra], 0)
         self._pending = None    # (ovf, final, sizes, stats, batch, caps, k)
         self._last_affected = np.empty(0, dtype=np.int64)
         self._commit_log = None   # serving: [(commit_idx, affected, rows)]
@@ -1078,6 +1093,11 @@ class DeviceEngine:
         self.last_rows_reaggregated = 0
         self.last_dims_reaggregated = 0
         self.last_recover_hits = 0
+        # the last_* counts summed over every batch resolved
+        self.shrink_events = 0
+        self.rows_reaggregated = 0
+        self.dims_reaggregated = 0
+        self.recover_hits = 0
         self.last_patch_events = 0      # bounded: device is refresh-all (0)
         self.last_deferred_rows = 0
         self.last_bound_violations = 0
@@ -1181,7 +1201,15 @@ class DeviceEngine:
         marks adopt the observed sizes plainly (batch-to-batch noise must
         not inflate the buckets); once settled, a channel that outgrows
         its bucket gets one extra 2x of headroom so a drifting stream pays
-        at most one recompile per doubling instead of one per crossing."""
+        at most one recompile per doubling instead of one per crossing.
+
+        A settled monotonic mark moves only when a batch needed more than
+        its channel's cap (the batch overflowed), and then to that need: a
+        record that still fits keeps the caps. Its four channels' sizes are
+        heavy-tailed, so under the crossing rule a record past 1/_HEADROOM
+        of some cap comes late and at a random batch, with a ~10 s compile
+        on a TPU and a 4x larger cap from then on; an overflow's need
+        already buckets at least one doubling up."""
         s = np.asarray(sizes, dtype=np.int64)
         self._notes += 1
         if self._hw is None:
@@ -1189,8 +1217,12 @@ class DeviceEngine:
             return
         grown = np.maximum(self._hw, s)
         if self._notes > self._SETTLE_NOTES:
-            crossed = self._bucketed(grown) > self._bucketed(self._hw)
-            grown = np.where(crossed, grown * 2, grown)
+            held = self._bucketed(self._hw)
+            if self.monotonic:
+                grown = np.where(s > held, s, self._hw)
+            else:
+                crossed = self._bucketed(grown) > held
+                grown = np.where(crossed, grown * 2, grown)
         self._hw = grown
 
     def _sentinel_batch(self) -> BatchDev:
@@ -1309,6 +1341,10 @@ class DeviceEngine:
             self._pending
         while _overflowed(overflow):
             self.retries += 1
+            sizes = np.asarray(sizes)
+            over = np.any(sizes[:, :len(caps[0])] > np.asarray(caps), axis=0)
+            for name, hit in zip(self.retries_by_channel, over):
+                self.retries_by_channel[name] += int(hit)
             # the failed attempt reported what it actually needed; aim the
             # retry straight at fitting caps (truncated attempts may still
             # under-report downstream hops — the rung fallback guarantees
@@ -1354,6 +1390,10 @@ class DeviceEngine:
                 self.last_rows_reaggregated = int(s[1])
                 self.last_dims_reaggregated = int(s[2])
                 self.last_recover_hits = int(s[3])
+                self.shrink_events += self.last_shrink_events
+                self.dims_reaggregated += self.last_dims_reaggregated
+                self.recover_hits += self.last_recover_hits
+            self.rows_reaggregated += self.last_rows_reaggregated
         if k_check is not None:
             np.testing.assert_allclose(np.asarray(self.state.k), k_check,
                                        err_msg="device k drifted from host "

@@ -287,3 +287,127 @@ def test_accelerator_lowerings_match_oracle(name):
     H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
     for h, href in zip(eng.host_H(), H_ref):
         np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)
+
+
+# ---------------------------------------------------------------------------
+# SHRINK counters, ladder retries by channel, and the in-half mirror's spans
+# ---------------------------------------------------------------------------
+_SHRINK = ("shrink_events", "rows_reaggregated", "dims_reaggregated",
+           "recover_hits")
+
+
+def _recorded_spans(monkeypatch):
+    """The names of the spans the device engine opens, in order."""
+    import contextlib
+
+    import repro.core.device_engine as de
+    names = []
+
+    def record(name):
+        names.append(name)
+        return contextlib.nullcontext()
+    monkeypatch.setattr(de, "span", record)
+    return names
+
+
+def test_shrink_counters_sum_the_batches_and_count_one_dim(monkeypatch):
+    """The cumulative SHRINK counters are the per-batch ``last_*`` values
+    summed; a delete of an edge whose source is the witness of exactly one
+    dimension of its destination's max re-aggregates that one cell, and
+    the in-half mirror's work has spans of its own."""
+    spans = _recorded_spans(monkeypatch)
+    wl, g, params, state = _setup("gs-max", n_layers=1)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=16)
+    C = np.asarray(state.C[1])
+    src, dst = g.coo()[:2]
+    one = [(int(u), int(v)) for u, v in zip(src, dst)
+           if np.count_nonzero(C[v] == u) == 1]
+    assert one, "no edge witnesses exactly one dimension"
+    u, v = one[0]
+    eng.apply_batch(UpdateBatch(edges=[EdgeUpdate(u, v, False)]))
+    assert eng.last_dims_reaggregated == 1
+    assert eng.last_shrink_events == 1 and eng.last_rows_reaggregated == 1
+    assert eng.last_recover_hits == 0
+    total = {k: getattr(eng, "last_" + k) for k in _SHRINK}
+    for b in _stream(g, np.random.default_rng(23)):
+        eng.apply_batch(b)
+        for k in _SHRINK:
+            total[k] += getattr(eng, "last_" + k)
+    assert total["dims_reaggregated"] > 1
+    assert {k: getattr(eng, k) for k in _SHRINK} == total
+    assert {"ripple.mirror.in_refresh", "ripple.mirror.refresh"} <= set(spans)
+    H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
+    for h, href in zip(eng.host_H(), H_ref):
+        np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)
+
+
+def test_pair_overflow_counts_under_its_channel():
+    """A batch whose (row, dim) pairs overflow their cap while every other
+    channel fits is one retry, counted once under ``pairs``."""
+    wl, g, params, state = _setup("gs-max", n=64, m=700)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=16)
+    eng.interpret = False        # the pair-flattened lowering of a TPU
+    for b in _stream(g, np.random.default_rng(29), n_batches=2):
+        eng.apply_batch(b)
+    before = eng.retries
+    by_channel = dict(eng.retries_by_channel)
+    # rows, edges and pulls at their ceilings; pairs at the smallest bucket
+    eng._hw = np.array([[1 << 20] * 3 + [0]] * wl.spec.n_layers)
+    rng = np.random.default_rng(0)
+    eng.apply_batch(UpdateBatch(features=[
+        FeatureUpdate(int(v), rng.normal(size=8).astype(np.float32))
+        for v in rng.choice(g.n, size=20, replace=False)]))
+    assert eng.last_dims_reaggregated > eng.min_bucket
+    assert eng.retries == before + 1
+    by_channel["pairs"] += 1
+    assert eng.retries_by_channel == by_channel
+    H_ref = _oracle_H(wl, params, g, eng.host_H()[0])
+    for h, href in zip(eng.host_H(), H_ref):
+        np.testing.assert_allclose(h, href, atol=ATOL, rtol=ATOL)
+
+
+def test_invertible_engine_keeps_no_shrink_counts(monkeypatch):
+    """A gc-s engine has no in-half mirror: its SHRINK counters stay 0,
+    its ladder has two channels, and no ``ripple.mirror.in_*`` span opens."""
+    spans = _recorded_spans(monkeypatch)
+    wl, g, params, state = _setup("gc-s", n=64, m=700)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=4)
+    for b in _stream(g, np.random.default_rng(31)):
+        eng.apply_batch(b)
+    assert eng.in_mirror is None
+    assert all(getattr(eng, k) == 0 for k in _SHRINK)
+    assert set(eng.retries_by_channel) == {"rows", "edges"}
+    assert eng.retries > 0
+    assert sum(eng.retries_by_channel.values()) >= eng.retries
+    assert "ripple.mirror.refresh" in spans
+    assert not [s for s in spans if s.startswith("ripple.mirror.in_")]
+
+
+@pytest.mark.parametrize("name", ["gs-max", "gc-s"])
+def test_settled_caps_move_on_overflow_or_crossing(name):
+    """Once the ladder has settled, a monotonic engine keeps its caps for a
+    record that still fits them and moves a channel's mark to a batch's
+    need only when that batch overflowed it; an invertible engine keeps the
+    crossing rule (a record whose headroomed bucket grows takes 2x)."""
+    wl, g, params, state = _setup(name, n=64, m=700)
+    eng = DeviceEngine(wl, params, g, state, min_bucket=16)
+    L, k = wl.spec.n_layers, 4 if name == "gs-max" else 3
+    eng._hw = np.full((L, k), 100, dtype=np.int64)
+    eng._notes = eng._SETTLE_NOTES + 1
+    caps = eng._caps(0)
+    assert caps[0][1] == 128         # edges: the bucket over 100 * 1.25
+    record = eng._hw.copy()
+    record[0, 1] = 110               # past 128 / 1.25, still inside 128
+    eng._note_sizes(record)
+    if name == "gs-max":
+        assert eng._caps(0) == caps
+        over = eng._hw.copy()
+        over[L - 1, 3] = 140         # past the pair cap of the last hop
+        eng._note_sizes(over)
+        want = np.full((L, k), 100)
+        want[L - 1, 3] = 140
+        np.testing.assert_array_equal(eng._hw, want)
+        assert eng._caps(0)[L - 1][3] == 256
+    else:
+        assert eng._hw[0, 1] == 220
+        assert eng._caps(0)[0][1] == 512
